@@ -6,6 +6,9 @@ import sys
 
 def main() -> None:
     from benchmarks import ckpt_bench, kernel_bench, paper_figs
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     rows: list[str] = ["name,us_per_call,derived"]
     sections = [

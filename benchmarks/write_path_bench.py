@@ -549,7 +549,9 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true", help="small inputs (CI smoke)")
     ap.add_argument("--out", type=Path, default=None)
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
 
+    use_compile_cache()
     if args.quick:
         cdc_bytes, scalar_bytes = 1 * MB, 64 * 1024
         fp_bytes = 4 * MB

@@ -10,11 +10,13 @@ This is the paper's technique integrated as a first-class framework feature:
 * restore hits the read path's consistency check, which repairs
   missing/invalid chunks from replicas — the paper §2.4 duplicate-write case.
 
-Device-fingerprint fast path (beyond paper, uses the Pallas kernel): before
+Device-fingerprint fast path (beyond paper, uses the Pallas kernels): before
 pulling a tensor to the host, fingerprint it on device and compare with the
 previous save; unchanged tensors are written by *reference* (refcount-only
 unicasts, no data motion). Falls back to a full write if any referenced
-chunk is missing (repair), so the fast path is safe.
+chunk is missing (repair), so the fast path is safe. A kernel failure
+raises out of ``save``; only leaves the kernels do not take (by type, see
+``_device_leaf``) skip the fast path.
 """
 
 from __future__ import annotations
@@ -24,6 +26,7 @@ import json
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import DedupCluster, ReadError
@@ -37,10 +40,11 @@ class CheckpointConfig:
     device_fp_fastpath: bool = True
     # Consolidated chunking surface for the device-fingerprint fast path:
     # kind "cdc" + device=True runs the fused chunk+fingerprint pipeline
-    # (ONE CDC launch + ONE fingerprint launch per save wave); kind "fixed"
-    # runs fixed-size chunking via fingerprint_tensor_chunks_many (still
-    # one fingerprint launch). When unset, built from the legacy fields
-    # below (accepted and mapped for one release).
+    # (ONE CDC launch + ONE fingerprint launch per byte-bounded save wave,
+    # kops.plan_waves); kind "fixed" runs fixed-size chunking (ONE
+    # fingerprint launch per wave).
+    # When unset, built from the legacy fields below (accepted and mapped
+    # for one release).
     chunk_spec: ChunkSpec | None = None
     # Legacy chunking spelling (.. deprecated:: prefer ``chunk_spec``):
     fp_chunk_bytes: int = 512 * 1024
@@ -77,27 +81,40 @@ def _leaf_paths(tree: Any) -> list[tuple[str, Any]]:
     return out
 
 
-def _serialize_leaf(leaf) -> bytes:
-    arr = np.asarray(jax.device_get(leaf))
-    if arr.dtype.name == "bfloat16":
-        arr = arr.view(np.uint16)
-        dtype_name = "bfloat16"
+def _device_leaf(leaf) -> bool:
+    """Whether the device fingerprint kernels take this leaf: a jax or numpy
+    array of bool, integer or float dtype. Numpy leaves wider than 4 bytes
+    need x64, or the device copy would round them and hide changes. Other
+    leaves (Python scalars, strings, complex or object arrays) get no device
+    fingerprint and are written in full on every save."""
+    if isinstance(leaf, jax.Array):
+        dt = leaf.dtype
+    elif isinstance(leaf, (np.ndarray, np.generic)):
+        dt = leaf.dtype
+        if dt.itemsize > 4 and not jax.config.jax_enable_x64:
+            return False
     else:
-        dtype_name = arr.dtype.name
-    header = json.dumps({"dtype": dtype_name, "shape": list(arr.shape)}).encode()
-    return len(header).to_bytes(4, "big") + header + arr.tobytes()
+        return False
+    return dt == np.bool_ or jnp.issubdtype(dt, jnp.integer) or jnp.issubdtype(dt, jnp.floating)
+
+
+def _serialize_leaf(leaf) -> bytes:
+    if isinstance(leaf, jax.Array):
+        # A jax.Array keeps the host copy np.asarray makes for as long as it
+        # lives; fetching a device copy leaves the caller's tree without one.
+        leaf = jnp.array(leaf, copy=True)
+    arr = np.asarray(jax.device_get(leaf))
+    header = json.dumps({"dtype": arr.dtype.name, "shape": list(arr.shape)}).encode()
+    # One copy of the payload: join reads the flat array's buffer in place.
+    flat = np.ascontiguousarray(arr.reshape(-1)).view(np.uint8)
+    return b"".join((len(header).to_bytes(4, "big"), header, flat.data))
 
 
 def _deserialize_leaf(data: bytes):
-    import jax.numpy as jnp
-
     hlen = int.from_bytes(data[:4], "big")
     meta = json.loads(data[4 : 4 + hlen].decode())
-    raw = data[4 + hlen :]
-    if meta["dtype"] == "bfloat16":
-        arr = np.frombuffer(raw, np.uint16).reshape(meta["shape"])
-        return jnp.asarray(arr).view(jnp.bfloat16)
-    arr = np.frombuffer(raw, np.dtype(meta["dtype"])).reshape(meta["shape"])
+    dtype = jnp.bfloat16 if meta["dtype"] == "bfloat16" else np.dtype(meta["dtype"])
+    arr = np.frombuffer(data, dtype, offset=4 + hlen).reshape(meta["shape"])
     return jnp.asarray(arr)
 
 
@@ -131,14 +148,15 @@ class DedupCheckpointer:
     # ------------------------------------------------------------------ save
     def save(self, name: str, tree: Any) -> dict[str, Any]:
         leaves = _leaf_paths(tree)
-        # Batched device fingerprinting: one kernel launch for ALL array
-        # leaves (vs one per leaf), then per-leaf ref-write decisions.
+        # Batched device fingerprinting: one launch pair per byte-bounded
+        # wave of device leaves (vs one per leaf), then per-leaf ref-write
+        # decisions.
         fp_cache = self._batch_device_fps(leaves)
         manifest = {"name": name, "leaves": []}
         full_writes: list[tuple[str, bytes]] = []
         for key, leaf in leaves:
             obj_name = f"{self.cfg.prefix}/{name}/{key}"
-            if self._ref_write(key, leaf, obj_name, fp_cache.get(key)):
+            if self._ref_write(key, obj_name, fp_cache.get(key)):
                 manifest["leaves"].append({"key": key, "object": obj_name, "ref": True})
                 self.stats["leaves_ref_only"] += 1
                 continue
@@ -177,60 +195,31 @@ class DedupCheckpointer:
         return manifest
 
     def _batch_device_fps(self, leaves: list[tuple[str, Any]]) -> dict[str, bytes]:
-        """Chunk + fingerprint every array leaf of the wave on device —
-        with ``device_cdc`` the whole pytree goes through ONE fused CDC
-        launch plus ONE fingerprint launch (content-defined chunks); without
-        it, fixed-size chunking in one fingerprint launch. Returns leafpath
-        -> raw fingerprint bytes; empty on any failure (callers fall back to
-        the per-leaf path)."""
+        """Chunk + fingerprint every device leaf on the device, in
+        byte-bounded waves (``kops.plan_waves``): with a CDC spec ONE fused
+        CDC launch plus ONE fingerprint launch per wave, with a fixed spec
+        ONE fingerprint launch per wave. A small pytree is one wave. Returns
+        leafpath -> raw fingerprint bytes. A kernel failure raises."""
         if not self.cfg.device_fp_fastpath:
             return {}
-        arr = [(k, leaf) for k, leaf in leaves if hasattr(leaf, "dtype")]
-        if not arr:
-            return {}
+        arr = [(k, leaf) for k, leaf in leaves if _device_leaf(leaf)]
         before = kops.launch_snapshot()
         try:
-            if self.spec.kind == "cdc":
-                out = self._fused_device_fps([leaf for _, leaf in arr])
-            else:
-                fps = kops.fingerprint_tensor_chunks_many(
-                    [leaf for _, leaf in arr], self.spec.target_bytes
-                )
-                out = [np.asarray(jax.device_get(f)).tobytes() for f in fps]
-            return {k: fp for (k, _), fp in zip(arr, out)}
-        except Exception:
-            return {}
+            fps = kops.leaf_fingerprints([leaf for _, leaf in arr], self.spec)
         finally:
             after = kops.launch_snapshot()
             self.stats["cdc_launches"] += after["cdc"] - before["cdc"]
             self.stats["fp_launches"] += after["fingerprint"] - before["fingerprint"]
+        return {k: fp for (k, _), fp in zip(arr, fps)}
 
-    def _fused_device_fps(self, tensors: list[Any]) -> list[bytes]:
-        """One fused chunk+fingerprint wave over every tensor's byte stream.
-        Per-leaf fingerprint bytes = the concatenated per-chunk device
-        fingerprints (CDC chunk boundaries, so any content change perturbs
-        both the chunking and the fingerprints)."""
-        streams = [kops.tensor_to_u8(t) for t in tensors]
-        res = kops.cdc_cut_and_fingerprint_many(streams, spec=self.spec)
-        out: list[bytes] = []
-        for _, _, fps, n_chunks in res:
-            nc = int(jax.device_get(n_chunks))
-            out.append(np.asarray(jax.device_get(fps))[:nc].tobytes())
-        return out
-
-    def _ref_write(self, key: str, leaf, obj_name: str, fp_bytes: bytes | None = None) -> bool:
+    def _ref_write(self, key: str, obj_name: str, fp_bytes: bytes | None) -> bool:
         """Device-fp fast path: if the tensor is unchanged since the last
-        save (per the Pallas fingerprint kernel), create the new object as a
+        save (per its device fingerprint), create the new object as a
         reference-only write against the previous one — refcount unicasts,
-        zero data motion. Returns True on success."""
-        if not self.cfg.device_fp_fastpath or not hasattr(leaf, "dtype"):
-            return False
+        zero data motion. Returns True on success; False for a leaf without
+        a device fingerprint."""
         if fp_bytes is None:
-            try:
-                fps = kops.fingerprint_tensor_chunks(leaf, self.spec.target_bytes)
-                fp_bytes = np.asarray(jax.device_get(fps)).tobytes()
-            except Exception:
-                return False
+            return False
         prev = self._last_device_fps.get(key)
         self._last_device_fps[key] = (fp_bytes, obj_name)
         if prev is None or prev[0] != fp_bytes:
@@ -247,10 +236,10 @@ class DedupCheckpointer:
         # batch, and each node serves its chunks in one ChunkReadBatch.
         ents = manifest["leaves"]
         blobs = self.cluster.read_objects([ent["object"] for ent in ents])
-        leaves = {
-            ent["key"]: _deserialize_leaf(data)
-            for ent, data in zip(ents, blobs)
-        }
+        leaves = {}
+        for i, ent in enumerate(ents):
+            leaves[ent["key"]] = _deserialize_leaf(blobs[i])
+            blobs[i] = None     # the host copy goes once its leaf is placed
         if like is None:
             return leaves
         flat, treedef = jax.tree_util.tree_flatten_with_path(like)

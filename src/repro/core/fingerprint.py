@@ -57,7 +57,7 @@ def fingerprint_many(chunks: Iterable[bytes]) -> list[Fingerprint]:
     """Batch fingerprinting: hash every chunk (of one object or of a whole
     write batch) in one pass. Results are exactly ``[sha256_fp(c) for c in
     chunks]``; batching keeps the hot write path to a single call site and
-    lets the device path (``repro.kernels.ops.fingerprint_tensor_chunks_many``)
+    lets the device path (``repro.kernels.ops.leaf_fingerprints``)
     swap in without touching callers."""
     sha = hashlib.sha256
     nb = FP_BYTES

@@ -15,7 +15,15 @@ import numpy as np
 from repro.core.chunking import GEAR_TABLE
 from repro.core.fingerprint import Fingerprint, device_fp
 from repro.kernels import ref
-from repro.kernels.cdc import cdc_cut_masks_pallas, cdc_hashes_pallas
+from repro.kernels.cdc import (
+    CUT_BLOCK_LEN,
+    WAVE_MAX_ROWS,
+    WAVE_ROW_BYTES,
+    cdc_cut_masks_pallas,
+    cdc_cuts_pallas,
+    cdc_hashes_pallas,
+    max_cuts,
+)
 from repro.kernels.fingerprint import fingerprint_chunks_pallas
 
 
@@ -49,14 +57,64 @@ def fingerprint_chunks(words: jnp.ndarray, *, use_pallas: bool | None = None) ->
     return ref.fingerprint_chunks(words)
 
 
+def _word_rows(flat_u32, chunk_words: int):
+    """(n,) uint32 -> (ceil(n / chunk_words), chunk_words), zero padded."""
+    pad = (-flat_u32.shape[0]) % chunk_words
+    return jnp.pad(flat_u32, (0, pad)).reshape(-1, chunk_words)
+
+
 @functools.partial(jax.jit, static_argnames=("chunk_words", "use_pallas"))
 def _fingerprint_tensor_impl(flat_u32, *, chunk_words: int, use_pallas: bool):
-    n = flat_u32.shape[0]
-    pad = (-n) % chunk_words
-    w = jnp.pad(flat_u32, (0, pad)).reshape(-1, chunk_words)
+    w = _word_rows(flat_u32, chunk_words)
     if use_pallas:
         return fingerprint_chunks_pallas(w)
     return ref.fingerprint_chunks(w)
+
+
+def _pack_words(u8: jnp.ndarray) -> jnp.ndarray:
+    """(4m,) uint8 -> (m,) little-endian uint32, from four strided byte
+    planes: a (m, 4) uint8 view would pad its minor dim to 128 lanes on the
+    TPU."""
+    return functools.reduce(
+        jnp.bitwise_or,
+        [u8[..., b::4].astype(jnp.uint32) << (8 * b) for b in range(4)],
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _interleave_matrix(k: int) -> np.ndarray:
+    """One-hot (k*128, k*128) matrix sending lane l of byte plane b to lane
+    k*l + b."""
+    perm = np.zeros((k * 128, k * 128), np.float32)
+    for b in range(k):
+        perm[b * 128 + np.arange(128), k * np.arange(128) + b] = 1
+    return perm
+
+
+def _interleave_bytes(planes: list[jnp.ndarray]) -> jnp.ndarray:
+    """k (m,) uint8 planes -> (k*m,) uint8 stream, out[k*i + b] =
+    planes[b][i].
+
+    Runs as a one-hot matmul on the MXU: bytes are exact in bf16 and each
+    output has one nonzero term. The direct route (bitcast to (m, k) uint8,
+    then flatten) pads the minor dim to 128 lanes on the TPU, 64x the
+    stream for bf16.
+    """
+    k, m = len(planes), planes[0].shape[0]
+    pad = (-m) % 128
+    p = jnp.concatenate(
+        [jnp.pad(q, (0, pad)).reshape(-1, 128) for q in planes], axis=1
+    ).astype(jnp.bfloat16)
+    out = jnp.dot(
+        p,
+        jnp.asarray(_interleave_matrix(k), jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    )
+    flat = jax.lax.optimization_barrier(out.astype(jnp.int32).reshape(-1))
+    return flat[: k * m].astype(jnp.uint8)
+
+
+_UINT = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
 
 
 def tensor_to_u32(x: jnp.ndarray) -> jnp.ndarray:
@@ -71,22 +129,22 @@ def tensor_to_u32(x: jnp.ndarray) -> jnp.ndarray:
     nbytes = flat.dtype.itemsize
     if nbytes % 4 == 0:
         return jax.lax.bitcast_convert_type(flat, jnp.uint32).reshape(-1)
-    # sub-word dtypes (u8/bf16/f16): widen via u8 packing
     as_u8 = tensor_to_u8(flat)
-    pad = (-as_u8.shape[0]) % 4
-    as_u8 = jnp.pad(as_u8, (0, pad))
-    g = as_u8.reshape(-1, 4).astype(jnp.uint32)
-    return g[:, 0] | (g[:, 1] << 8) | (g[:, 2] << 16) | (g[:, 3] << 24)
+    return _pack_words(jnp.pad(as_u8, (0, (-as_u8.shape[0]) % 4)))
 
 
+@jax.jit
 def tensor_to_u8(x: jnp.ndarray) -> jnp.ndarray:
-    """Bitcast any tensor to its flat byte stream, staying on device."""
+    """Bitcast any tensor to its flat little-endian byte stream, staying on
+    device."""
     flat = x.reshape(-1)
     if flat.dtype == jnp.bool_:
         flat = flat.astype(jnp.uint8)
-    if flat.dtype == jnp.uint8:
-        return flat
-    return jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(-1)
+    k = flat.dtype.itemsize
+    u = jax.lax.bitcast_convert_type(flat, _UINT[k])
+    if k == 1:
+        return u
+    return _interleave_bytes([(u >> (8 * b)).astype(jnp.uint8) for b in range(k)])
 
 
 def fingerprint_tensor_chunks(
@@ -103,46 +161,6 @@ def fingerprint_tensor_chunks(
     flat = tensor_to_u32(x)
     _count_launch("fingerprint")
     return _fingerprint_tensor_impl(flat, chunk_words=chunk_words, use_pallas=use_pallas)
-
-
-def fingerprint_tensor_chunks_many(
-    tensors: list[jnp.ndarray],
-    chunk_bytes: int = 512 * 1024,
-    *,
-    use_pallas: bool | None = None,
-) -> list[jnp.ndarray]:
-    """Batched ``fingerprint_tensor_chunks``: fingerprint every tensor's
-    chunks in ONE kernel launch instead of one launch per tensor.
-
-    Each tensor is padded to a chunk_words multiple independently (so results
-    are bit-identical to per-tensor calls), the chunk rows are stacked into a
-    single (total_chunks, chunk_words) matrix, and the kernel runs once.
-    Returns one (n_chunks_i, 4) uint32 array per input tensor."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not tensors:
-        return []
-    chunk_words = max(128, chunk_bytes // 4)
-    rows: list[jnp.ndarray] = []
-    counts: list[int] = []
-    for x in tensors:
-        flat = tensor_to_u32(x)
-        pad = (-flat.shape[0]) % chunk_words
-        w = jnp.pad(flat, (0, pad)).reshape(-1, chunk_words)
-        rows.append(w)
-        counts.append(w.shape[0])
-    stacked = jnp.concatenate(rows, axis=0)
-    _count_launch("fingerprint")
-    if use_pallas:
-        fps = fingerprint_chunks_pallas(stacked)
-    else:
-        fps = ref.fingerprint_chunks(stacked)
-    out: list[jnp.ndarray] = []
-    off = 0
-    for c in counts:
-        out.append(fps[off : off + c])
-        off += c
-    return out
 
 
 def device_fps_to_host(fps_u32: jnp.ndarray) -> list[Fingerprint]:
@@ -239,45 +257,51 @@ def fp_row_words(max_size: int) -> tuple[int, int]:
     return payload, max(128, width)
 
 
-def _max_cuts(n: int, min_size: int) -> int:
-    """Static bound on the number of cuts in an n-byte stream: every cut
-    advances the chunk start by at least min_size + 1 bytes."""
-    return n // (min_size + 1) + 1
+def _chunk_table(cutpos, n_cuts, *, n: int):
+    """Per-stream chunk table from its cut positions.
 
-
-def _chunk_rows(stream_u8, cut_mask, *, n: int, min_size: int, max_size: int):
-    """Segment-reduce one stream into fixed-width fingerprint rows.
-
-    Returns (rows (M, width) u32, cutpos (m_cut,) i32, n_cuts i32 scalar,
-    n_chunks i32 scalar) where M = _max_cuts(n) + 1 >= n_chunks; rows past
-    n_chunks are garbage and must be sliced off by the caller.
+    cutpos: (m_cut,) i32, the first ``n_cuts`` valid. Returns (starts (M,)
+    i32, lens (M,) i32, n_chunks i32 scalar) with M = m_cut + 1 >= n_chunks.
+    Rows past n_chunks have length 0 and must be sliced off by the caller.
     """
-    row_words, width = fp_row_words(max_size)
-    row_bytes = row_words * 4
-    m_cut = _max_cuts(n, min_size)
-    cutpos = jnp.nonzero(cut_mask, size=m_cut, fill_value=n)[0].astype(jnp.int32)
-    n_cuts = jnp.sum(cut_mask).astype(jnp.int32)
+    m_cut = cutpos.shape[0]
     starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), cutpos + 1])
     row_idx = jnp.arange(m_cut + 1, dtype=jnp.int32)
     cut_ext = jnp.concatenate([cutpos, jnp.full((1,), n - 1, jnp.int32)])
     ends = jnp.where(row_idx < n_cuts, cut_ext[row_idx], jnp.int32(n - 1))
-    lens = jnp.clip(ends - starts + 1, 0, row_bytes)
-    padded = jnp.pad(stream_u8, (0, row_bytes))
-    rows_u8 = jax.vmap(
-        lambda s: jax.lax.dynamic_slice(padded, (s,), (row_bytes,))
-    )(jnp.clip(starts, 0, n))
-    col = jnp.arange(row_bytes, dtype=jnp.int32)
-    rows_u8 = jnp.where(col[None, :] < lens[:, None], rows_u8, jnp.uint8(0))
-    g = rows_u8.reshape(-1, row_words, 4).astype(jnp.uint32)
-    words = g[:, :, 0] | (g[:, :, 1] << 8) | (g[:, :, 2] << 16) | (g[:, :, 3] << 24)
-    rows = (
-        jnp.zeros((m_cut + 1, width), jnp.uint32)
-        .at[:, :row_words].set(words)
-        .at[:, row_words].set(lens.astype(jnp.uint32))
-    )
+    lens = jnp.maximum(ends - starts + 1, 0)
     # Tail chunk exists unless the last cut landed exactly on byte n-1.
     n_chunks = n_cuts + (jnp.take(starts, n_cuts) < n).astype(jnp.int32)
-    return rows, cutpos, n_cuts, n_chunks
+    return jnp.clip(starts, 0, n), lens, n_chunks
+
+
+def _pack_rows(flat_u8, starts, lens, *, max_size: int):
+    """Gather every chunk of a wave into its fixed-width fingerprint row.
+
+    flat_u8 holds the wave's streams back to back; ``starts`` are absolute
+    offsets into it. A row's slice may run past its chunk into the next
+    stream: those bytes are masked by ``lens``. Words are packed little
+    endian by ``_pack_words`` (the bitcast of a (…, 4) uint8 view pads to
+    128 lanes on the TPU and compiles for minutes).
+    """
+    row_words, width = fp_row_words(max_size)
+    row_bytes = row_words * 4
+    padded = jnp.pad(flat_u8, (0, row_bytes))
+    rows_u8 = jax.vmap(
+        lambda s: jax.lax.dynamic_slice(padded, (s,), (row_bytes,))
+    )(starts)
+    col = jnp.arange(row_bytes, dtype=jnp.int32)
+    rows_u8 = jnp.where(col[None, :] < lens[:, None], rows_u8, jnp.uint8(0))
+    words = _pack_words(rows_u8)
+    m = starts.shape[0]
+    return jnp.concatenate(
+        [
+            words,
+            jnp.minimum(lens, row_bytes).astype(jnp.uint32)[:, None],
+            jnp.zeros((m, width - row_words - 1), jnp.uint32),
+        ],
+        axis=1,
+    )
 
 
 @functools.partial(
@@ -293,33 +317,39 @@ def _cut_and_fp_impl(
     lens = [s.shape[0] for s in streams]
     tvs = [jnp.take(_gear_jnp(), s.astype(jnp.int32)) for s in streams]
     if use_pallas or interpret:
-        masks = cdc_cut_masks_pallas(
-            tvs, mask=mask, min_size=min_size, max_size=max_size,
-            interpret=interpret, block_len=block_len,
-        )
+        cuts = [
+            (pos, nc)
+            for _, pos, nc in cdc_cuts_pallas(
+                tvs, mask=mask, min_size=min_size, max_size=max_size,
+                interpret=interpret, block_len=block_len,
+            )
+        ]
     else:
         # Per-stream hashing so each stream sees its own zero prefix window,
         # exactly like the kernel's per-stream halo.
-        masks = [
-            ref.cdc_cut_mask(
-                (ref.cdc_hashes(tv) & jnp.uint32(mask)) == 0,
-                n, min_size, max_size,
+        cuts = []
+        for tv, n in zip(tvs, lens):
+            m = ref.cdc_cut_mask(
+                (ref.cdc_hashes(tv) & jnp.uint32(mask)) == 0, n, min_size, max_size
             )
-            for tv, n in zip(tvs, lens)
-        ]
-    per_stream = [
-        _chunk_rows(s, m, n=n, min_size=min_size, max_size=max_size)
-        for s, m, n in zip(streams, masks, lens)
-    ]
-    stacked = jnp.concatenate([rows for rows, _, _, _ in per_stream])
+            pos = jnp.nonzero(m, size=max_cuts(n, min_size), fill_value=n)[0]
+            cuts.append((pos.astype(jnp.int32), jnp.sum(m).astype(jnp.int32)))
+    tables = [_chunk_table(pos, nc, n=n) for (pos, nc), n in zip(cuts, lens)]
+    bases = np.cumsum([0] + lens[:-1])
+    stacked = _pack_rows(
+        jnp.concatenate(streams),
+        jnp.concatenate([t[0] + int(b) for t, b in zip(tables, bases)]),
+        jnp.concatenate([t[1] for t in tables]),
+        max_size=max_size,
+    )
     if use_pallas:
         fps = fingerprint_chunks_pallas(stacked)
     else:
         fps = ref.fingerprint_chunks(stacked)
     out, off = [], 0
-    for rows, cutpos, n_cuts, n_chunks in per_stream:
-        out.append((cutpos, n_cuts, fps[off : off + rows.shape[0]], n_chunks))
-        off += rows.shape[0]
+    for (cutpos, n_cuts), (starts, _, n_chunks) in zip(cuts, tables):
+        out.append((cutpos, n_cuts, fps[off : off + starts.shape[0]], n_chunks))
+        off += starts.shape[0]
     return out
 
 
@@ -352,8 +382,6 @@ def cdc_cut_and_fingerprint_many(
     if use_pallas is None:
         use_pallas = _on_tpu()
     if block_len is None:
-        from repro.kernels.cdc import CUT_BLOCK_LEN
-
         block_len = CUT_BLOCK_LEN
     assert min_size >= 1, "pass a normalized ChunkingSpec (min_size >= 1)"
     zero = jnp.zeros((), jnp.int32)
@@ -435,3 +463,138 @@ def cdc_cut_offsets(
         cand = (ref.cdc_hashes(tvals) & jnp.uint32(mask)) == 0
         m = ref.cdc_cut_mask(cand, n, min_size, max_size)
     return np.flatnonzero(np.asarray(jax.device_get(m)))
+
+
+# ---------------------------------------------------------------------------
+# Byte-bounded save waves: device fingerprints of a whole pytree, one launch
+# pair per wave, with each wave's fingerprint rows within wave_row_cap.
+# ---------------------------------------------------------------------------
+
+
+def _row_bytes(spec) -> int:
+    """Bytes of one fingerprint row under ``spec`` (a ``ChunkSpec``)."""
+    if spec.kind == "cdc":
+        return fp_row_words(spec.max_bytes)[1] * 4
+    return max(128, spec.target_bytes // 4) * 4
+
+
+def wave_rows(n: int, spec) -> int:
+    """Fingerprint rows an n-byte stream adds to a device wave: one per
+    possible chunk. A wave's temporaries scale with its rows."""
+    if spec.kind == "cdc":
+        return max_cuts(n, spec.min_bytes) + 1
+    return -(-n // _row_bytes(spec))
+
+
+def wave_row_cap(spec) -> int:
+    """Rows one wave may hold: WAVE_ROW_BYTES of rows, and at most
+    WAVE_MAX_ROWS so the cut kernel's SMEM tables stay small."""
+    return max(1, min(WAVE_ROW_BYTES // _row_bytes(spec), WAVE_MAX_ROWS))
+
+
+def segment_bytes(spec) -> int:
+    """Largest stream whose rows fill one wave alone. Larger leaves are cut
+    into segments of at most this many bytes."""
+    cap = wave_row_cap(spec)
+    if spec.kind == "cdc":
+        return max(1, (cap - 2) * (spec.min_bytes + 1) + spec.min_bytes)
+    return cap * _row_bytes(spec)
+
+
+def _unit_bytes(leaf) -> int:
+    """Bytes per slicing unit: a row of the leaf's (-1, last dim) view, or
+    an element of a 0-D or 1-D leaf."""
+    return (leaf.shape[-1] if leaf.ndim >= 2 else 1) * leaf.dtype.itemsize
+
+
+def plan_waves(leaves, spec) -> list[list[tuple[int, int, int]]]:
+    """Pack the leaves' segments into device waves.
+
+    A segment is (leaf index, first unit, unit count) in ``_unit_bytes``
+    units, so slicing one never copies the whole leaf. Segments hold at
+    most ``segment_bytes(spec)`` (one unit at least). A wave takes segments
+    in leaf order while their ``wave_rows`` sum stays within
+    ``wave_row_cap(spec)``; a lone segment always forms a wave.
+    """
+    seg_max = segment_bytes(spec)
+    cap = wave_row_cap(spec)
+    waves: list[list[tuple[int, int, int]]] = []
+    cur: list[tuple[int, int, int]] = []
+    cur_rows = 0
+    for i, leaf in enumerate(leaves):
+        if leaf.size == 0:
+            continue
+        unit = _unit_bytes(leaf)
+        n_units = leaf.size * leaf.dtype.itemsize // unit
+        step = max(1, seg_max // unit)
+        for a in range(0, n_units, step):
+            k = min(step, n_units - a)
+            cost = wave_rows(k * unit, spec)
+            if cur and cur_rows + cost > cap:
+                waves.append(cur)
+                cur, cur_rows = [], 0
+            cur.append((i, a, k))
+            cur_rows += cost
+    if cur:
+        waves.append(cur)
+    return waves
+
+
+@functools.partial(jax.jit, static_argnames=("sizes",))
+def _segments(leaves, starts, *, sizes):
+    """A wave's segments as flat arrays, in a program of their own: segment
+    j is ``sizes[j]`` units of ``leaves[j]`` from unit ``starts[j]`` on.
+    With the slices fused into the byte split of ``tensor_to_u8``, the wave
+    programs of the qwen2.5-32b 2-layer tree took 257.8 s to compile for
+    v5e, against 70.1 s apart."""
+    out = []
+    for x, a, k in zip(leaves, starts, sizes):
+        view = x.reshape(-1, x.shape[-1]) if x.ndim >= 2 else x.reshape(-1)
+        out.append(jax.lax.dynamic_slice_in_dim(view, a, k).reshape(-1))
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "use_pallas"))
+def _wave_impl(segs, *, spec, use_pallas: bool):
+    """One save wave as one program: fingerprint each flat segment's
+    chunks, content-defined (fused cut + fingerprint) or fixed-size.
+    Returns (fps, n_chunks) per segment. Waves of equal segment lengths
+    share one compilation."""
+    if spec.kind == "cdc":
+        res = _cut_and_fp_impl(
+            tuple(jax.lax.optimization_barrier(tensor_to_u8(s)) for s in segs),
+            **spec.kernel_kwargs(),
+            use_pallas=use_pallas, interpret=False, block_len=CUT_BLOCK_LEN,
+        )
+        return [(fps, n_chunks) for _, _, fps, n_chunks in res]
+    chunk_words = _row_bytes(spec) // 4
+    rows = [_word_rows(tensor_to_u32(s), chunk_words) for s in segs]
+    stacked = jnp.concatenate(rows)
+    fps = fingerprint_chunks_pallas(stacked) if use_pallas else ref.fingerprint_chunks(stacked)
+    bounds = np.cumsum([0] + [r.shape[0] for r in rows])
+    return [(fps[a:b], b - a) for a, b in zip(bounds[:-1], bounds[1:])]
+
+
+def leaf_fingerprints(leaves, spec) -> list[bytes]:
+    """Device fingerprint bytes of each leaf, for change detection.
+
+    Runs the waves of ``plan_waves``: each slices its segments, then runs
+    ONE fused CDC launch plus ONE fingerprint launch (CDC spec) or ONE
+    fingerprint launch (fixed spec), then one ``device_get``. A leaf's
+    bytes are its segments' chunk fingerprints in order. CDC boundaries restart at segment edges,
+    so fingerprints compare only under the same spec.
+    """
+    leaves = [x if isinstance(x, jax.Array) else jnp.asarray(x) for x in leaves]
+    out: list[list[bytes]] = [[] for _ in leaves]
+    for wave in plan_waves(leaves, spec):
+        if spec.kind == "cdc":
+            _count_launch("cdc")
+        _count_launch("fingerprint")
+        segs = _segments(
+            [leaves[i] for i, _, _ in wave], [a for _, a, _ in wave],
+            sizes=tuple(k for _, _, k in wave),
+        )
+        res = _wave_impl(segs, spec=spec, use_pallas=_on_tpu())
+        for (i, _, _), (fps, nc) in zip(wave, jax.device_get(res)):
+            out[i].append(np.asarray(fps)[: int(nc)].tobytes())
+    return [b"".join(p) for p in out]

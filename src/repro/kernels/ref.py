@@ -88,7 +88,7 @@ def cdc_boundaries(tvals: jnp.ndarray, mask: int) -> jnp.ndarray:
 
 # ---------------------------------------------------------------------------
 # Min/max-size cut selection over the candidate mask — the jnp oracle the
-# fused Pallas kernel (cdc.cdc_cut_mask_pallas) must match bit-exactly, which
+# fused Pallas kernel (cdc.cdc_cuts_pallas) must match bit-exactly, which
 # in turn matches the scalar chunk_cdc_scalar loop:
 #
 #   start = 0

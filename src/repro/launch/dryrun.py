@@ -1,5 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = os.environ.get("DRYRUN_XLA_EXTRA", "") + " --xla_force_host_platform_device_count=512"
+# Compile-only tool on host devices: never take an accelerator, which may
+# belong to another process (the launcher that re-execs this module).
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

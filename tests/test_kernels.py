@@ -99,7 +99,7 @@ def test_tensor_to_u32_matches_numpy_bytes(dtype, shape):
     """tensor_to_u32 must pack the tensor's raw little-endian bytes into
     uint32 words — exactly np.frombuffer(arr.tobytes() + pad, '<u4') — for
     every dtype, including the wide (f64/i64) and sub-word (u8/bool) paths."""
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         n = int(np.prod(shape))
         if dtype == "bool":
             host = (RNG.integers(0, 2, size=shape) > 0)
