@@ -32,6 +32,7 @@ import numpy as np
 from repro.core import DedupCluster, ReadError
 from repro.core.chunking import ChunkSpec
 from repro.kernels import ops as kops
+from repro.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,56 +144,68 @@ class DedupCheckpointer:
             # one-CDC-launch + one-fingerprint-launch-per-wave contract
             "cdc_launches": 0,
             "fp_launches": 0,
+            # the device waves: waves run, bytes of the leaves fingerprinted
+            # on the device, their device chunks, and those leaves; then the
+            # bytes of the leaves written by reference
+            "waves": 0,
+            "wave_bytes": 0,
+            "chunks": 0,
+            "leaves_waved": 0,
+            "ref_bytes": 0,
         }
 
     # ------------------------------------------------------------------ save
     def save(self, name: str, tree: Any) -> dict[str, Any]:
-        leaves = _leaf_paths(tree)
-        # Batched device fingerprinting: one launch pair per byte-bounded
-        # wave of device leaves (vs one per leaf), then per-leaf ref-write
-        # decisions.
-        fp_cache = self._batch_device_fps(leaves)
-        manifest = {"name": name, "leaves": []}
-        full_writes: list[tuple[str, bytes]] = []
-        for key, leaf in leaves:
-            obj_name = f"{self.cfg.prefix}/{name}/{key}"
-            if self._ref_write(key, obj_name, fp_cache.get(key)):
-                manifest["leaves"].append({"key": key, "object": obj_name, "ref": True})
-                self.stats["leaves_ref_only"] += 1
-                continue
-            data = _serialize_leaf(leaf)
-            full_writes.append((obj_name, data))
-            manifest["leaves"].append({"key": key, "object": obj_name, "ref": False})
-        mbytes = json.dumps(manifest).encode()
-        # One batched write transaction for all full leaves + the manifest,
-        # riding the cross-object coalesced transport path: one ChunkOpBatch
-        # unicast per storage node for the WHOLE checkpoint, and chunks
-        # shared between leaves (replicated experts, tied embeddings) ship
-        # their bytes once — later leaves ride ref-only ops. write_objects
-        # commits items in order and raises at the first failure, so the
-        # writes_ok delta counts exactly the committed leaves — including on
-        # a mid-batch failure.
-        # With ``wave_bytes`` set the session streams the batch in bounded
-        # waves instead (chunk+fingerprint wave k+1 while wave k's batches
-        # are on the wire; O(wave) host dirty bytes), and a configured
-        # presence cache elides CIT probes for chunks repeated across saves.
-        writer = (
-            self.session.put_many
-            if self.session is not None
-            else self.cluster.write_objects
-        )
-        ok_before = self.cluster.stats.writes_ok
-        try:
-            writer(
-                full_writes + [(f"{self.cfg.prefix}/{name}/MANIFEST", mbytes)]
+        with span("ckpt.save", name=name):
+            leaves = _leaf_paths(tree)
+            # Batched device fingerprinting: one launch pair per byte-bounded
+            # wave of device leaves (vs one per leaf), then per-leaf ref-write
+            # decisions.
+            fp_cache = self._batch_device_fps(leaves)
+            manifest = {"name": name, "leaves": []}
+            full_writes: list[tuple[str, bytes]] = []
+            for key, leaf in leaves:
+                obj_name = f"{self.cfg.prefix}/{name}/{key}"
+                if self._ref_write(key, obj_name, fp_cache.get(key)):
+                    manifest["leaves"].append({"key": key, "object": obj_name, "ref": True})
+                    self.stats["leaves_ref_only"] += 1
+                    self.stats["ref_bytes"] += int(leaf.nbytes)
+                    continue
+                with span("ckpt.serialize"):
+                    data = _serialize_leaf(leaf)
+                full_writes.append((obj_name, data))
+                manifest["leaves"].append({"key": key, "object": obj_name, "ref": False})
+            mbytes = json.dumps(manifest).encode()
+            # One batched write transaction for all full leaves + the manifest,
+            # riding the cross-object coalesced transport path: one ChunkOpBatch
+            # unicast per storage node for the WHOLE checkpoint, and chunks
+            # shared between leaves (replicated experts, tied embeddings) ship
+            # their bytes once — later leaves ride ref-only ops. write_objects
+            # commits items in order and raises at the first failure, so the
+            # writes_ok delta counts exactly the committed leaves — including on
+            # a mid-batch failure.
+            # With ``wave_bytes`` set the session streams the batch in bounded
+            # waves instead (chunk+fingerprint wave k+1 while wave k's batches
+            # are on the wire; O(wave) host dirty bytes), and a configured
+            # presence cache elides CIT probes for chunks repeated across saves.
+            writer = (
+                self.session.put_many
+                if self.session is not None
+                else self.cluster.write_objects
             )
-        finally:
-            committed = min(self.cluster.stats.writes_ok - ok_before, len(full_writes))
-            self.stats["leaves_written"] += committed
-            self.stats["bytes_sent"] += sum(len(d) for _, d in full_writes[:committed])
-        # drain async flag flips (the paper's consistency manager)
-        self.cluster.tick(2)
-        return manifest
+            items = full_writes + [(f"{self.cfg.prefix}/{name}/MANIFEST", mbytes)]
+            ok_before = self.cluster.stats.writes_ok
+            try:
+                with span("ckpt.put", objects=len(items), bytes=sum(len(d) for _, d in items)):
+                    writer(items)
+            finally:
+                committed = min(self.cluster.stats.writes_ok - ok_before, len(full_writes))
+                self.stats["leaves_written"] += committed
+                self.stats["bytes_sent"] += sum(len(d) for _, d in full_writes[:committed])
+            # drain async flag flips (the paper's consistency manager)
+            with span("ckpt.commit"):
+                self.cluster.tick(2)
+            return manifest
 
     def _batch_device_fps(self, leaves: list[tuple[str, Any]]) -> dict[str, bytes]:
         """Chunk + fingerprint every device leaf on the device, in
@@ -203,13 +216,20 @@ class DedupCheckpointer:
         if not self.cfg.device_fp_fastpath:
             return {}
         arr = [(k, leaf) for k, leaf in leaves if _device_leaf(leaf)]
+        xs = [leaf for _, leaf in arr]
+        waves = len(kops.plan_waves(xs, self.spec))
         before = kops.launch_snapshot()
         try:
-            fps = kops.leaf_fingerprints([leaf for _, leaf in arr], self.spec)
+            with span("ckpt.waves", leaves=len(xs), waves=waves):
+                fps = kops.leaf_fingerprints(xs, self.spec)
         finally:
             after = kops.launch_snapshot()
             self.stats["cdc_launches"] += after["cdc"] - before["cdc"]
             self.stats["fp_launches"] += after["fingerprint"] - before["fingerprint"]
+        self.stats["waves"] += waves
+        self.stats["wave_bytes"] += sum(int(x.nbytes) for x in xs)
+        self.stats["chunks"] += sum(len(fp) // 16 for fp in fps)
+        self.stats["leaves_waved"] += len(fps)
         return {k: fp for (k, _), fp in zip(arr, fps)}
 
     def _ref_write(self, key: str, obj_name: str, fp_bytes: bytes | None) -> bool:
@@ -224,41 +244,49 @@ class DedupCheckpointer:
         self._last_device_fps[key] = (fp_bytes, obj_name)
         if prev is None or prev[0] != fp_bytes:
             return False
-        ofp = self.cluster.write_object_by_ref(obj_name, prev[1])
+        with span("ckpt.ref_write"):
+            ofp = self.cluster.write_object_by_ref(obj_name, prev[1])
         return ofp is not None
 
     # --------------------------------------------------------------- restore
     def restore(self, name: str, like: Any | None = None) -> Any:
-        mbytes = self.cluster.read_object(f"{self.cfg.prefix}/{name}/MANIFEST")
-        manifest = json.loads(mbytes.decode())
-        # One coalesced restore for every leaf: leaves sharing chunks (the
-        # dedup win this checkpointer exists for) are fetched once per
-        # batch, and each node serves its chunks in one ChunkReadBatch.
-        ents = manifest["leaves"]
-        blobs = self.cluster.read_objects([ent["object"] for ent in ents])
-        leaves = {}
-        for i, ent in enumerate(ents):
-            leaves[ent["key"]] = _deserialize_leaf(blobs[i])
-            blobs[i] = None     # the host copy goes once its leaf is placed
-        if like is None:
-            return leaves
-        flat, treedef = jax.tree_util.tree_flatten_with_path(like)
-        out = []
-        for path, leaf in flat:
-            key = "/".join(str(p) for p in path)
-            if key not in leaves:
-                raise ReadError(f"checkpoint {name} missing leaf {key}")
-            out.append(leaves[key])
-        return jax.tree_util.tree_unflatten(treedef, out)
+        with span("ckpt.restore", name=name):
+            with span("ckpt.restore.read"):
+                mbytes = self.cluster.read_object(f"{self.cfg.prefix}/{name}/MANIFEST")
+                manifest = json.loads(mbytes.decode())
+                # One coalesced restore for every leaf: leaves sharing chunks
+                # (the dedup win this checkpointer exists for) are fetched
+                # once per batch, and each node serves its chunks in one
+                # ChunkReadBatch.
+                ents = manifest["leaves"]
+                blobs = self.cluster.read_objects([ent["object"] for ent in ents])
+            with span("ckpt.restore.place"):
+                leaves = {}
+                for i, ent in enumerate(ents):
+                    leaves[ent["key"]] = _deserialize_leaf(blobs[i])
+                    blobs[i] = None     # the host copy goes once its leaf is placed
+            if like is None:
+                return leaves
+            flat, treedef = jax.tree_util.tree_flatten_with_path(like)
+            out = []
+            for path, leaf in flat:
+                key = "/".join(str(p) for p in path)
+                if key not in leaves:
+                    raise ReadError(f"checkpoint {name} missing leaf {key}")
+                out.append(leaves[key])
+            return jax.tree_util.tree_unflatten(treedef, out)
 
     def delete(self, name: str) -> None:
-        mbytes = self.cluster.read_object(f"{self.cfg.prefix}/{name}/MANIFEST")
-        manifest = json.loads(mbytes.decode())
-        # ref'd objects belong to an earlier checkpoint; delete only our own
-        own = {e["object"] for e in manifest["leaves"] if not e.get("ref")}
-        for obj in own:
-            self.cluster.delete_object(obj)
-        self.cluster.delete_object(f"{self.cfg.prefix}/{name}/MANIFEST")
+        with span("ckpt.delete", name=name):
+            mbytes = self.cluster.read_object(f"{self.cfg.prefix}/{name}/MANIFEST")
+            manifest = json.loads(mbytes.decode())
+            # Only the leaves written in full are deleted. Objects written by
+            # reference are not: each is an object of its own, with its own
+            # chunk references, and stays in the object map.
+            own = {e["object"] for e in manifest["leaves"] if not e.get("ref")}
+            for obj in own:
+                self.cluster.delete_object(obj)
+            self.cluster.delete_object(f"{self.cfg.prefix}/{name}/MANIFEST")
 
     def list_checkpoints(self) -> list[str]:
         names = set()

@@ -65,6 +65,7 @@ from repro.core.messages import (
 from repro.core.node import ChunkMissing, NodeDown, StorageNode
 from repro.core.placement import ClusterMap, place
 from repro.core.transport import MessageDropped, Transport
+from repro.tracing import span
 
 # fault injector signature: (event, context-dict) -> None. May raise
 # TransactionAbort or call cluster.crash_node() to model failures.
@@ -362,10 +363,11 @@ class DedupCluster:
         self._flush_presence_invalidations()
 
     def run_gc(self) -> dict[str, list[Fingerprint]]:
-        removed = {nid: n.run_gc(self.now) for nid, n in self.nodes.items()}
-        # Each node's GC hook queued its reclaimed fps (when sessions are
-        # registered); fan the invalidations out now, after every node ran.
-        self._flush_presence_invalidations()
+        with span("cluster.gc"):
+            removed = {nid: n.run_gc(self.now) for nid, n in self.nodes.items()}
+            # Each node's GC hook queued its reclaimed fps (when sessions are
+            # registered); fan the invalidations out now, after every node ran.
+            self._flush_presence_invalidations()
         return removed
 
     # -------------------------------------------------- client sessions

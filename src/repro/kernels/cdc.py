@@ -113,6 +113,7 @@ def cdc_hashes_pallas(
         out_specs=pl.BlockSpec((rows, per_row), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((total_rows, per_row), jnp.uint32),
         interpret=interpret,
+        name="cdc_hashes_pallas",
     )(haloed)
     return out.reshape(-1)[:n]
 
@@ -290,6 +291,7 @@ def cdc_cuts_pallas(
         ],
         scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
         interpret=interpret,
+        name="cdc_cuts_pallas",
     )(
         jnp.asarray(lens, jnp.int32),
         jnp.asarray(bases, jnp.int32),

@@ -124,5 +124,9 @@ def fingerprint_chunks_pallas(
         out_specs=pl.BlockSpec((tc, LANES), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((wp.shape[0], LANES), jnp.uint32),
         interpret=interpret,
+        # The op's name in compiled programs and device traces, whatever
+        # jitted function holds the kernel (chipbench's fp_roofline.save
+        # finds the kernel by it).
+        name="fingerprint_chunks_pallas",
     )(wp)
     return out[:n_chunks]

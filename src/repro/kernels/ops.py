@@ -25,6 +25,7 @@ from repro.kernels.cdc import (
     max_cuts,
 )
 from repro.kernels.fingerprint import fingerprint_chunks_pallas
+from repro.tracing import span
 
 
 def _on_tpu() -> bool:
@@ -580,21 +581,30 @@ def leaf_fingerprints(leaves, spec) -> list[bytes]:
 
     Runs the waves of ``plan_waves``: each slices its segments, then runs
     ONE fused CDC launch plus ONE fingerprint launch (CDC spec) or ONE
-    fingerprint launch (fixed spec), then one ``device_get``. A leaf's
-    bytes are its segments' chunk fingerprints in order. CDC boundaries restart at segment edges,
+    fingerprint launch (fixed spec), then one ``device_get``. Each wave is
+    a ``ckpt.wave`` span, split into ``ckpt.wave.dispatch`` (slicing and
+    the wave program enqueued), ``ckpt.wave.fetch`` (the host waits on the
+    device) and ``ckpt.wave.unpack``. A leaf's bytes are its segments'
+    chunk fingerprints in order. CDC boundaries restart at segment edges,
     so fingerprints compare only under the same spec.
     """
     leaves = [x if isinstance(x, jax.Array) else jnp.asarray(x) for x in leaves]
     out: list[list[bytes]] = [[] for _ in leaves]
-    for wave in plan_waves(leaves, spec):
-        if spec.kind == "cdc":
-            _count_launch("cdc")
-        _count_launch("fingerprint")
-        segs = _segments(
-            [leaves[i] for i, _, _ in wave], [a for _, a, _ in wave],
-            sizes=tuple(k for _, _, k in wave),
-        )
-        res = _wave_impl(segs, spec=spec, use_pallas=_on_tpu())
-        for (i, _, _), (fps, nc) in zip(wave, jax.device_get(res)):
-            out[i].append(np.asarray(fps)[: int(nc)].tobytes())
+    for w, wave in enumerate(plan_waves(leaves, spec)):
+        nbytes = sum(k * _unit_bytes(leaves[i]) for i, _, k in wave)
+        with span("ckpt.wave", index=w, segments=len(wave), bytes=nbytes):
+            if spec.kind == "cdc":
+                _count_launch("cdc")
+            _count_launch("fingerprint")
+            with span("ckpt.wave.dispatch"):
+                segs = _segments(
+                    [leaves[i] for i, _, _ in wave], [a for _, a, _ in wave],
+                    sizes=tuple(k for _, _, k in wave),
+                )
+                res = _wave_impl(segs, spec=spec, use_pallas=_on_tpu())
+            with span("ckpt.wave.fetch"):
+                res = jax.device_get(res)
+            with span("ckpt.wave.unpack"):
+                for (i, _, _), (fps, nc) in zip(wave, res):
+                    out[i].append(np.asarray(fps)[: int(nc)].tobytes())
     return [b"".join(p) for p in out]

@@ -109,3 +109,31 @@ def test_save_wave_of_embedding_fits_chip(one_chip):
     compiled = ops._wave_impl.lower([seg], spec=CKPT_SPEC, use_pallas=True).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES // 4
+
+
+def _kernel_names(text: str) -> list[str]:
+    """Names of the Pallas kernels in a compiled program's HLO text, without
+    their ``.N`` suffix."""
+    return sorted(
+        line.split("=", 1)[0].strip().rsplit(".", 1)[0]
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    )
+
+
+@pytest.mark.parametrize("program, want", [
+    ("wave", ["%cdc_cuts_pallas", "%fingerprint_chunks_pallas"]),
+    ("hashes", ["%cdc_hashes_pallas"]),
+])
+def test_kernels_keep_their_names(one_chip, program, want):
+    """Each kernel's op carries its own name, whatever jitted function holds
+    it: device traces show ops by these names, and the wave program as
+    module ``jit__wave_impl``."""
+    if program == "wave":
+        seg = jax.ShapeDtypeStruct((2**20,), jnp.bfloat16, sharding=one_chip)
+        compiled = ops._wave_impl.lower([seg], spec=CKPT_SPEC, use_pallas=True).compile()
+        assert compiled.as_text().startswith("HloModule jit__wave_impl,")
+    else:
+        tvals = jax.ShapeDtypeStruct((2**20,), jnp.uint32, sharding=one_chip)
+        compiled, _ = _compile(cdc_hashes_pallas, tvals)
+    assert _kernel_names(compiled.as_text()) == want
