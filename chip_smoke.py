@@ -84,7 +84,7 @@ class Phases:
 
 def check_kernels(spec) -> None:
     from repro.core.chunking import (
-        GEAR_TABLE, chunk_cdc, chunk_cdc_scalar, window_hashes,
+        chunk_cdc, chunk_cdc_scalar, window_hashes,
     )
     from repro.kernels import ops, ref
     from repro.kernels.cdc import cdc_hashes_pallas
@@ -96,7 +96,7 @@ def check_kernels(spec) -> None:
     words = ops.tensor_to_u32(data).reshape(64, -1)
     assert same_bits(fingerprint_chunks_pallas(words), ref.fingerprint_chunks(words))
 
-    tvals = jnp.take(jnp.asarray(np.array(GEAR_TABLE, np.uint32)), data.astype(jnp.int32))
+    tvals = ops.gear_values(data)
     hashes = cdc_hashes_pallas(tvals)
     assert same_bits(hashes, ref.cdc_hashes(tvals))
     assert np.array_equal(np.asarray(hashes), window_hashes(host.tobytes()))

@@ -30,19 +30,28 @@ import numpy as np
 DEFAULT_CHUNK_SIZE = 512 * 1024
 
 # --- windowed gear hash (must match kernels/ref.py::cdc_window_hash) --------
-_GEAR_MULT = 0x9E3779B1          # 32-bit golden-ratio multiplier
 _WINDOW = 32                     # bytes of context per boundary decision
+
+# The gear table is generated, not data: for byte b,
+#   T[b] = fmix32(GEAR_SEED + (b + 1) * GEAR_STEP)   (mod 2**32)
+# with murmur3's finalizer fmix32 (multipliers GEAR_MIX1, GEAR_MIX2). The
+# device computes T[b] from these same constants
+# (``repro.kernels.ops.gear_values``) instead of looking it up.
+GEAR_SEED = 0x243F6A88
+GEAR_STEP = 0x9E3779B9
+GEAR_MIX1 = 0x85EBCA6B
+GEAR_MIX2 = 0xC2B2AE35
 
 
 def _gear_table() -> list[int]:
     # Deterministic pseudo-random byte->u32 table (splitmix-ish), no RNG dep.
     tbl = []
-    x = 0x243F6A88
+    x = GEAR_SEED
     for _ in range(256):
-        x = (x + 0x9E3779B9) & 0xFFFFFFFF
+        x = (x + GEAR_STEP) & 0xFFFFFFFF
         z = x
-        z = ((z ^ (z >> 16)) * 0x85EBCA6B) & 0xFFFFFFFF
-        z = ((z ^ (z >> 13)) * 0xC2B2AE35) & 0xFFFFFFFF
+        z = ((z ^ (z >> 16)) * GEAR_MIX1) & 0xFFFFFFFF
+        z = ((z ^ (z >> 13)) * GEAR_MIX2) & 0xFFFFFFFF
         z = z ^ (z >> 16)
         tbl.append(z)
     return tbl

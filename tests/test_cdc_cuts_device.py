@@ -16,7 +16,6 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
 from repro.core.chunking import (
-    GEAR_TABLE,
     ChunkingSpec,
     cdc_mask,
     chunk_cdc,
@@ -35,7 +34,12 @@ try:
 except ImportError:  # pragma: no cover - CI always has hypothesis
     HAVE_HYPOTHESIS = False
 
-_GEAR = jnp.asarray(np.array(GEAR_TABLE, dtype=np.uint32))
+# Every byte value, then runs of 0x00 and 0xFF longer than the max_size of
+# the cases that use it (so they end in forced cuts), and every value again.
+ALL_BYTES = (
+    bytes(range(256)) * 3 + b"\x00" * 1500 + bytes(range(255, -1, -1))
+    + b"\xff" * 1500 + bytes(range(256))
+)
 
 
 def _scalar_cuts(data: bytes, spec: ChunkingSpec) -> np.ndarray:
@@ -60,7 +64,7 @@ def _scalar_cuts(data: bytes, spec: ChunkingSpec) -> np.ndarray:
 def _device_cuts(data: bytes, spec: ChunkingSpec, *, interpret: bool, block_len=512):
     spec = spec.normalized()
     mask = cdc_mask(spec.chunk_size)
-    tv = jnp.take(_GEAR, jnp.asarray(np.frombuffer(data, np.uint8)).astype(jnp.int32))
+    tv = kops.gear_values(jnp.asarray(np.frombuffer(data, np.uint8)))
     if interpret:
         m = cdc_cut_masks_pallas(
             [tv], mask=mask, min_size=spec.min_size, max_size=spec.max_size,
@@ -92,7 +96,8 @@ def _check_spec(data: bytes, spec: ChunkingSpec, *, interpret: bool) -> None:
 # --------------------------------------------------------------- seeded sweep
 
 SWEEP = [
-    # (n, target, min_size, max_size) — 0 means "let normalized() pick"
+    # (n, target, min_size, max_size) — n is a length of random bytes or a
+    # stream itself; 0 sizes mean "let normalized() pick"
     (3000, 256, 64, 1024),
     (4096, 64, 1, 97),
     (100, 1024, 60, 4096),      # whole stream shorter than min_size window
@@ -101,13 +106,17 @@ SWEEP = [
     (2048, 128, 100, 101),      # max_size == min_size + 1: hard-cut dominated
     (1500, 64, 50, 50),         # max_size == min_size: hard = lo always
     (5000, 512, 0, 0),
+    pytest.param(ALL_BYTES, 64, 32, 700, id="all-bytes"),
 ]
 
 
 @pytest.mark.parametrize("n,target,mn,mx", SWEEP)
 def test_device_cuts_match_scalar_oracle(n, target, mn, mx):
-    rng = np.random.default_rng(n * 31 + target)
-    data = rng.integers(0, 256, size=n, dtype=np.uint8).tobytes()
+    if isinstance(n, bytes):
+        data = n
+    else:
+        rng = np.random.default_rng(n * 31 + target)
+        data = rng.integers(0, 256, size=n, dtype=np.uint8).tobytes()
     spec = ChunkingSpec("cdc", target, mn, mx)
     _check_spec(data, spec, interpret=False)
     _check_spec(data, spec, interpret=True)
@@ -148,11 +157,18 @@ def test_chunk_cdc_device_backend_bit_identical():
     assert fingerprint_many(dev) == fingerprint_many(chunk_cdc_scalar(data, spec))
 
 
-@pytest.mark.parametrize("interpret", [False, True])
-def test_fused_fingerprints_match_host_rows(interpret):
+@pytest.mark.parametrize("interpret, all_bytes", [
+    pytest.param(False, False, id="False"),
+    pytest.param(True, False, id="True"),
+    pytest.param(False, True, id="all-bytes-False"),
+    pytest.param(True, True, id="all-bytes-True"),
+])
+def test_fused_fingerprints_match_host_rows(interpret, all_bytes):
     rng = np.random.default_rng(23)
     spec = ChunkingSpec("cdc", 256, 64, 700)
     streams = [rng.integers(0, 256, size=n, dtype=np.uint8) for n in (3000, 64, 1, 517)]
+    if all_bytes:
+        streams = [np.frombuffer(ALL_BYTES, np.uint8), streams[1]]
     res = kops.cdc_cut_and_fingerprint_many(
         [jnp.asarray(s) for s in streams],
         mask=cdc_mask(spec.chunk_size),
